@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import dispatch
+from repro.utils.scopes import PAYLOAD_ENCODE
 
 KINDS = ("identity", "topk", "int8", "bf16")
 
@@ -148,18 +149,19 @@ class PayloadTransform:
         backend.
         """
         del backend  # elementwise encodes have no kernel variant
-        x = jnp.asarray(x, jnp.float32)
-        if self.kind == "identity":
-            return x, jnp.zeros_like(x)
-        if self.kind == "topk":
-            thresh = topk_threshold(x, self.k)
-            keep = jnp.abs(x) >= thresh[..., None]
-            sent = jnp.where(keep, x, 0.0)
-        elif self.kind == "int8":
-            sent = dequantize_int8(*quantize_int8(x))
-        else:  # bf16
-            sent = x.astype(jnp.bfloat16).astype(jnp.float32)
-        return sent, x - sent
+        with jax.named_scope(PAYLOAD_ENCODE):
+            x = jnp.asarray(x, jnp.float32)
+            if self.kind == "identity":
+                return x, jnp.zeros_like(x)
+            if self.kind == "topk":
+                thresh = topk_threshold(x, self.k)
+                keep = jnp.abs(x) >= thresh[..., None]
+                sent = jnp.where(keep, x, 0.0)
+            elif self.kind == "int8":
+                sent = dequantize_int8(*quantize_int8(x))
+            else:  # bf16
+                sent = x.astype(jnp.bfloat16).astype(jnp.float32)
+            return sent, x - sent
 
     def reduce_mean(self, x, *, backend: str = "auto"):
         """Compressed server reduction: ``(mean over agents, residual)``.
@@ -173,7 +175,8 @@ class PayloadTransform:
         x = jnp.asarray(x, jnp.float32)
         m = x.shape[-2]
         if self.kind == "topk":
-            thresh = topk_threshold(x, self.k)
+            with jax.named_scope(PAYLOAD_ENCODE):
+                thresh = topk_threshold(x, self.k)
             ssum, residual = dispatch.topk_scatter(x, thresh, backend=backend)
             return ssum / m, residual
         sent, residual = self.encode(x, backend=backend)

@@ -38,6 +38,11 @@ operand, and ``cfg.env_params`` built from a traced ``hetero_scale``
 likewise. Under the sweep's vmap the mask batches to ``(S, m, tau)`` and
 the env params to per-run pytrees; the period length ``tau`` stays static
 (it is the inner scan length). See DESIGN.md §11.
+
+Both cores name their layers on the device: each call site opens a
+``jax.named_scope`` from ``repro.utils.scopes`` (rollout, PPO gradient,
+local step, sync, evaluation), so a profiler trace attributes every op to
+the outermost one; the payload transform nests its encode scope inside.
 """
 from __future__ import annotations
 
@@ -73,6 +78,7 @@ from repro.rl.rollout import (
     fleet_rollout,
 )
 from repro.sharding import shard_agents
+from repro.utils import scopes
 from repro.utils.pytree import tree_l2_norm
 
 
@@ -213,27 +219,37 @@ def _collect(cfg: FedRLConfig, env_params, params_m, env_state, key):
 
     Returns ``(env_state, grads_m, losses, nas)``. The legacy shared-env
     branch reproduces the original key discipline exactly (one rollout key);
-    the fleet branch additionally splits a minibatch-shuffle key.
+    the fleet branch additionally splits a minibatch-shuffle key. The
+    rollout runs under the ``fed_rollout`` scope, the gradients under
+    ``fed_ppo_grad`` (the evaluation calls the same helpers under its own).
     """
     if cfg.fleet:
         rk, gk = jax.random.split(key)
-        env_state, traj = fleet_rollout(
-            cfg.env, env_params, params_m, env_state, rk, cfg.minibatch
-        )
-        grads, losses = _fleet_grads(
-            cfg, params_m, env_params, traj, env_state, gk,
-            epochs=cfg.ppo_epochs, n_minibatches=cfg.n_minibatches,
-        )
+        with jax.named_scope(scopes.ROLLOUT):
+            env_state, traj = fleet_rollout(
+                cfg.env, env_params, params_m, env_state, rk, cfg.minibatch
+            )
+            nas = jnp.mean(traj["rew"])
+        with jax.named_scope(scopes.PPO_GRAD):
+            grads, losses = _fleet_grads(
+                cfg, params_m, env_params, traj, env_state, gk,
+                epochs=cfg.ppo_epochs, n_minibatches=cfg.n_minibatches,
+            )
     else:
-        env_state, traj = _rollout(cfg, params_m, env_state, key, cfg.minibatch)
-        grads, losses = _agent_grads(cfg, params_m, traj, env_state)
-    return env_state, grads, losses, jnp.mean(traj["rew"])
+        with jax.named_scope(scopes.ROLLOUT):
+            env_state, traj = _rollout(cfg, params_m, env_state, key,
+                                       cfg.minibatch)
+            nas = jnp.mean(traj["rew"])
+        with jax.named_scope(scopes.PPO_GRAD):
+            grads, losses = _agent_grads(cfg, params_m, traj, env_state)
+    return env_state, grads, losses, nas
 
 
 def _reset(cfg: FedRLConfig, env_params, key):
-    if cfg.fleet:
-        return fleet_reset(cfg.env, env_params, key, cfg.B)
-    return env_reset(cfg.env, key)
+    with jax.named_scope(scopes.ROLLOUT):
+        if cfg.fleet:
+            return fleet_reset(cfg.env, env_params, key, cfg.B)
+        return env_reset(cfg.env, key)
 
 
 def _eval_grad_norm(cfg: FedRLConfig, server_params, env_params=None):
@@ -354,7 +370,8 @@ def _run_fedrl_tree(cfg: FedRLConfig, key) -> tuple[Any, dict]:
             cfg, env_params, params_m, env_state, rk
         )
         offset = jnp.mod(k, tau)
-        params_m = strat.local_update(params_m, grads, offset, cfg.eta)
+        with jax.named_scope(scopes.LOCAL_STEP):
+            params_m = strat.local_update(params_m, grads, offset, cfg.eta)
         k = k + 1
 
         def do_sync(p):
@@ -362,7 +379,8 @@ def _run_fedrl_tree(cfg: FedRLConfig, key) -> tuple[Any, dict]:
             return jax.tree.map(lambda l: jnp.broadcast_to(l, (m,) + l.shape), avg)
 
         synced = jnp.equal(jnp.mod(k, tau), 0)
-        params_m = jax.lax.cond(synced, do_sync, lambda p: p, params_m)
+        with jax.named_scope(scopes.SYNC):
+            params_m = jax.lax.cond(synced, do_sync, lambda p: p, params_m)
         return (params_m, env_state, k, key), {"nas": nas, "loss": losses.mean(),
                                                "synced": synced}
 
@@ -373,8 +391,9 @@ def _run_fedrl_tree(cfg: FedRLConfig, key) -> tuple[Any, dict]:
         (params_m, _, k, key), ms = jax.lax.scan(
             update, (params_m, env_state, k, key), None, length=updates_per_epoch
         )
-        server = strat.server_average(params_m)
-        grad_sq = _eval_grad_norm(cfg, server, env_params)
+        with jax.named_scope(scopes.EVALUATE):
+            server = strat.server_average(params_m)
+            grad_sq = _eval_grad_norm(cfg, server, env_params)
         out = {
             "nas": ms["nas"].mean(),
             "loss": ms["loss"].mean(),
@@ -386,7 +405,8 @@ def _run_fedrl_tree(cfg: FedRLConfig, key) -> tuple[Any, dict]:
     (params_m, k, key), metrics = jax.lax.scan(
         epoch, carry, None, length=cfg.n_epochs
     )
-    server = strat.server_average(params_m)
+    with jax.named_scope(scopes.EVALUATE):
+        server = strat.server_average(params_m)
     return server, metrics
 
 
@@ -425,17 +445,20 @@ def _run_fedrl_flat(cfg: FedRLConfig, key) -> tuple[Any, dict]:
         flat, opt_state, comm_state, env_state, k, key = carry
         flat = shard_agents(flat)
         key, rk = jax.random.split(key)
-        params_m = tree_view(flat)
+        with jax.named_scope(scopes.PPO_GRAD):
+            params_m = tree_view(flat)
         env_state, grads, losses, nas = _collect(
             cfg, env_params, params_m, env_state, rk
         )
-        g_flat = jax.vmap(spec.ravel_one)(grads)
-        if dtype is not None:
-            g_flat = g_flat.astype(dtype)
+        with jax.named_scope(scopes.PPO_GRAD):
+            g_flat = jax.vmap(spec.ravel_one)(grads)
+            if dtype is not None:
+                g_flat = g_flat.astype(dtype)
         offset = jnp.mod(k, tau)
-        flat, opt_state, comm_state = strat.flat_local_step(
-            flat, g_flat, offset, cfg.eta, opt, opt_state, comm_state
-        )
+        with jax.named_scope(scopes.LOCAL_STEP):
+            flat, opt_state, comm_state = strat.flat_local_step(
+                flat, g_flat, offset, cfg.eta, opt, opt_state, comm_state
+            )
         k = k + 1
 
         # Boundary index of the sync `k` just completed (k is
@@ -453,9 +476,10 @@ def _run_fedrl_flat(cfg: FedRLConfig, key) -> tuple[Any, dict]:
             return f, s, cs
 
         synced = jnp.equal(jnp.mod(k, tau), 0)
-        flat, opt_state, comm_state = jax.lax.cond(
-            synced, do_sync, lambda args: args, (flat, opt_state, comm_state)
-        )
+        with jax.named_scope(scopes.SYNC):
+            flat, opt_state, comm_state = jax.lax.cond(
+                synced, do_sync, lambda args: args, (flat, opt_state, comm_state)
+            )
         return (flat, opt_state, comm_state, env_state, k, key), {
             "nas": nas, "loss": losses.mean(), "synced": synced,
         }
@@ -476,7 +500,8 @@ def _run_fedrl_flat(cfg: FedRLConfig, key) -> tuple[Any, dict]:
             update, (flat, opt_state, comm_state, env_state, k, key), None,
             length=updates_per_epoch,
         )
-        grad_sq = _eval_grad_norm(cfg, server_view(flat), env_params)
+        with jax.named_scope(scopes.EVALUATE):
+            grad_sq = _eval_grad_norm(cfg, server_view(flat), env_params)
         out = {
             "nas": ms["nas"].mean(),
             "loss": ms["loss"].mean(),
@@ -488,7 +513,8 @@ def _run_fedrl_flat(cfg: FedRLConfig, key) -> tuple[Any, dict]:
     (flat, opt_state, comm_state, k, key), metrics = jax.lax.scan(
         epoch, carry, None, length=cfg.n_epochs
     )
-    return server_view(flat), metrics
+    with jax.named_scope(scopes.EVALUATE):
+        return server_view(flat), metrics
 
 
 def expected_gradient_norm(metrics) -> float:
