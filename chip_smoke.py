@@ -11,7 +11,9 @@ critic) and with fleets of m = 1,024 agents:
   (a) device    the first JAX device must be a TPU, else exit 1.
   (b) kernels   every dispatch primitive on ``backend="pallas"`` against
                 ``backend="jnp"`` at m = 1,024, plus the sparse gossip
-                gather at m = 10,000 with k = 8 neighbours.
+                gather at m = 10,000 with k = 8 neighbours, and the top-512
+                payload threshold at m = 10,000, bitwise against
+                ``lax.top_k``.
   (c) training  ``run_fedrl_core`` (the traced core of ``run_fedrl``) on a
                 1,024-agent figure-eight fleet, 8 envs per agent, tau = 10,
                 four 100-step epochs (two federation periods), for VPA /
@@ -39,6 +41,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src
 M = 1024          # agents in the kernel and training phases
 M_GOSSIP = 10_000  # agents in the sparse gossip check
 K_GOSSIP = 8      # neighbours per agent in the sparse gossip check
+K_TOPK = 512      # entries a top-k payload row keeps
 ENVS = 8          # parallel envs per agent
 TAU = 10
 
@@ -135,7 +138,7 @@ def primitive_cases(m: int, n: int, m_gossip: int, key):
             x, p, backend=b), (g, mix)),
         ("row_mean", lambda b: lambda x: dispatch.row_mean(x, backend=b), (g,)),
         ("topk_scatter", lambda b: lambda x: dispatch.topk_scatter(
-            x, topk_threshold(x, 512), backend=b), (g,)),
+            x, topk_threshold(x, K_TOPK), backend=b), (g,)),
         ("consensus_gather", lambda b: lambda x, i, v: dispatch.consensus_gather(
             x, i, v, backend=b), (g_big, jnp.asarray(nl.idx), nl_w)),
     ]
@@ -153,6 +156,29 @@ def phase_kernels(backend: str, m: int, n: int, m_gossip: int) -> None:
              backend=backend, compile_s=f"{c_s:.3f}", run_s=f"{r_s:.4f}",
              max_abs_dev=f"{dev:.3e}", tol=f"{tol:.3e}")
         check(dev <= tol, f"{name}: pallas deviates {dev} from jnp (tol {tol})")
+    check_topk_threshold(backend, m_gossip, n, jax.random.key(1))
+
+
+def check_topk_threshold(backend: str, m: int, n: int, key) -> None:
+    """The radix-select threshold against ``lax.top_k``, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels import dispatch
+
+    x = jax.random.normal(key, (m, n), jnp.float32)
+    got, c_s, r_s, _ = compile_and_run(
+        lambda v: dispatch.topk_threshold(v, K_TOPK, backend=backend), x)
+    want = compile_and_run(
+        lambda v: dispatch.topk_threshold(v, K_TOPK, backend="jnp"), x)[0]
+    differ = int(np.sum(np.asarray(got).view(np.int32)
+                        != np.asarray(want).view(np.int32)))
+    emit("b kernels", prim="topk_threshold", shape=x.shape, k=K_TOPK,
+         backend=backend, compile_s=f"{c_s:.3f}", run_s=f"{r_s:.4f}",
+         rows_differing=differ)
+    check(differ == 0, f"topk_threshold: {differ} of {m} rows differ from "
+          f"lax.top_k")
 
 
 # --- (c) training ---------------------------------------------------------------

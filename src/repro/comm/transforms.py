@@ -11,6 +11,9 @@ the period sync, gossip payloads on the consensus path. Four kinds:
   is *threshold* form: keep every entry with ``|x| >= kth largest |x|`` of
   its row (magnitude ties at the threshold are all kept, so the jnp
   ``segment_sum`` reference and the fused Pallas kernel agree exactly).
+  The threshold is ``lax.top_k`` of ``|x|`` on the jnp backend and an exact
+  radix select (``repro.kernels.topk_threshold``, no sort) on the kernel
+  backends; both give the same bits.
   Wire size: k (value, index) pairs = 8k bytes per event.
 * ``int8``     — symmetric per-row quantization, ``s = max|x| / 127``,
   ``q = round(x/s)`` in [-127, 127]; n + 4 bytes per event (payload + fp32
@@ -28,7 +31,8 @@ optimizer moments (the PR-2 fp32-moments pattern).
 
 ``reduce_mean`` is the compressed server reduction: the mean over the agent
 axis of the encoded payloads, accumulated in fp32 on every backend. The
-top-k path routes through ``dispatch.topk_scatter`` — the fused
+top-k path takes its threshold from ``dispatch.topk_threshold`` and routes
+through ``dispatch.topk_scatter`` — the fused
 select + scatter-accumulate kernel — so the dense ``sent`` matrix is never
 materialised on kernel backends.
 """
@@ -40,22 +44,24 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import dispatch
-from repro.utils.scopes import PAYLOAD_ENCODE
+from repro.utils.scopes import PAYLOAD_ENCODE, TOPK_SELECT
 
 KINDS = ("identity", "topk", "int8", "bf16")
 
 
-def topk_threshold(x, k: int):
+def topk_threshold(x, k: int, backend: str = "jnp"):
     """Per-row top-k magnitude threshold: the k-th largest ``|x|`` per row.
 
     ``x``: ``(..., n)``. Returns the ``(...,)`` thresholds; an entry is kept
     iff ``|x| >= threshold`` (ties included — the one selection rule shared
     by the jnp reference and the Pallas kernel, so parity is exact).
+    ``dispatch.topk_threshold`` computes it: ``lax.top_k`` of ``|x|`` on
+    ``jnp`` (a full sort of each row on the TPU), the radix-select kernel on
+    ``pallas``/``interpret``, bitwise equal. The ops run under the
+    ``fed_topk_select`` scope.
     """
-    n = x.shape[-1]
-    if not 1 <= k <= n:
-        raise ValueError(f"topk_threshold: need 1 <= k <= {n}, got k={k}")
-    return jax.lax.top_k(jnp.abs(x), k)[0][..., -1]
+    with jax.named_scope(TOPK_SELECT):
+        return dispatch.topk_threshold(x, k, backend=backend)
 
 
 def quantize_int8(x):
@@ -144,17 +150,16 @@ class PayloadTransform:
         previous error-feedback residual in *before* encoding. ``sent`` is
         the receiver-visible fp32 reconstruction, ``residual = x - sent``
         (exact in fp32: kept/dequantized values subtract out bitwise for
-        top-k). The ``backend`` is accepted for interface symmetry with
-        :meth:`reduce_mean`; the dense encodes are elementwise jnp on every
-        backend.
+        top-k). The ``backend`` picks the top-k threshold's path
+        (:func:`topk_threshold`); the keep mask and the dense encodes are
+        elementwise jnp on every backend.
         """
-        del backend  # elementwise encodes have no kernel variant
         with jax.named_scope(PAYLOAD_ENCODE):
             x = jnp.asarray(x, jnp.float32)
             if self.kind == "identity":
                 return x, jnp.zeros_like(x)
             if self.kind == "topk":
-                thresh = topk_threshold(x, self.k)
+                thresh = topk_threshold(x, self.k, backend)
                 keep = jnp.abs(x) >= thresh[..., None]
                 sent = jnp.where(keep, x, 0.0)
             elif self.kind == "int8":
@@ -176,7 +181,7 @@ class PayloadTransform:
         m = x.shape[-2]
         if self.kind == "topk":
             with jax.named_scope(PAYLOAD_ENCODE):
-                thresh = topk_threshold(x, self.k)
+                thresh = topk_threshold(x, self.k, backend)
             ssum, residual = dispatch.topk_scatter(x, thresh, backend=backend)
             return ssum / m, residual
         sent, residual = self.encode(x, backend=backend)

@@ -481,12 +481,37 @@ def row_mean(g, *, backend: str = "auto", block_n=None):
     return row_mean_pallas(g, block_n=block_n, interpret=(b == "interpret"))
 
 
+def topk_threshold(x, k: int, *, backend: str = "auto"):
+    """Per-row top-k magnitude threshold: the k-th largest ``|x|`` per row.
+
+    ``x``: ``(..., n)``, e.g. ``(m, n)`` payload rows or ``(S, m, n)`` with a
+    leading sweep axis. Returns the ``(...,)`` thresholds. The jnp path is
+    ``lax.top_k`` of ``|x|`` (on the TPU a full sort of every row); the
+    kernel backends run the exact radix select of
+    ``repro.kernels.topk_threshold`` over the rows viewed as one ``(rows,
+    n)`` fp32 matrix. Both return the bit pattern of an element of the row,
+    so the thresholds are bitwise equal.
+    """
+    n = x.shape[-1]
+    if not 1 <= k <= n:
+        raise ValueError(f"topk_threshold: need 1 <= k <= {n}, got k={k}")
+    b = resolve_backend(backend)
+    if b == "jnp":
+        return jax.lax.top_k(jnp.abs(x), k)[0][..., -1]
+    from repro.kernels.topk_threshold import topk_threshold_pallas
+
+    rows = x.reshape(-1, n).astype(jnp.float32)
+    thresh = topk_threshold_pallas(rows, k, interpret=(b == "interpret"))
+    return thresh.reshape(x.shape[:-1]).astype(x.dtype)
+
+
 def topk_scatter(x, thresh, *, backend: str = "auto", block_n=None):
     """Fused top-k select + scatter-accumulate: the compressed server reduction.
 
     ``x``: ``(m, n)`` payload rows (or ``(S, m, n)`` with a leading sweep
     axis); ``thresh``: ``(m,)`` (or ``(S, m)``) per-agent magnitude
-    thresholds, normally ``repro.comm.topk_threshold(x, k)``. Selection is
+    thresholds, normally ``topk_threshold(x, k)`` on the same backend
+    (``lax.top_k`` on jnp, the radix-select kernel otherwise). Selection is
     threshold form — keep ``|x| >= thresh`` with ties included — so the jnp
     reference and the Pallas kernel pick identical entries. Returns
     ``(sent_sum, residual)``: the ``(n,)`` sum of the selected entries over
@@ -757,6 +782,11 @@ def _primitive_hot_path(prim: str, backend: str) -> Callable[[], HotPathEntry]:
                 fn=lambda g: row_mean(g, backend=backend),
                 args=(buf(m, n),),
             )
+        if prim == "topk_threshold":
+            return HotPathEntry(
+                fn=lambda x: topk_threshold(x, 8, backend=backend),
+                args=(buf(m, n),),
+            )
         if prim == "topk_scatter":
             return HotPathEntry(
                 fn=lambda x, t: topk_scatter(x, t, backend=backend),
@@ -783,7 +813,7 @@ def _primitive_hot_path(prim: str, backend: str) -> Callable[[], HotPathEntry]:
 
 DISPATCH_PRIMITIVES = (
     "decay_accum", "scale_rows", "consensus_mix", "consensus_gather",
-    "row_mean", "topk_scatter", "policy_infer",
+    "row_mean", "topk_threshold", "topk_scatter", "policy_infer",
 )
 
 # The pallas backend proper needs a TPU to lower; jnp + interpret cover both

@@ -5,7 +5,9 @@ and accumulates it into the server sum. Done naively that is three passes
 (materialise the dense ``sent`` matrix, reduce it, subtract for the error
 residual); this kernel fuses them into one bandwidth-bound sweep over the
 ``(m, n)`` payload: per n-block it selects by the precomputed per-agent
-magnitude threshold (``|x| >= tau_i`` — ties included, matching the jnp
+magnitude threshold (``dispatch.topk_threshold``: the radix-select kernel of
+``repro.kernels.topk_threshold`` on the kernel backends, ``lax.top_k`` on
+jnp; ``|x| >= tau_i`` — ties included, matching the jnp
 ``segment_sum`` reference in ``repro.kernels.dispatch.topk_scatter``),
 accumulates the selected values over the agent axis in fp32, and writes the
 per-agent residual ``x - sent`` for the error-feedback carry.

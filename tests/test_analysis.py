@@ -328,7 +328,7 @@ def test_jxa004_flags_declared_but_unused_donation():
 
 
 def test_audit_registry_covers_the_whole_hot_path():
-    """All five dispatch primitives on both CPU-executable backends, the
+    """The dispatch primitives on both CPU-executable backends, the
     compressed comm reductions, both driver cores, and the sweep engine's
     static-point fn are registered."""
     from repro.analysis.jaxpr_audit import collect_entries
@@ -337,7 +337,7 @@ def test_audit_registry_covers_the_whole_hot_path():
     assert import_findings == []
     names = set(factories)
     for prim in ("decay_accum", "scale_rows", "consensus_mix", "row_mean",
-                 "topk_scatter"):
+                 "topk_threshold", "topk_scatter"):
         for backend in ("jnp", "interpret"):
             assert f"dispatch.{prim}[{backend}]" in names
     # the compressed server reductions register their own entries: the

@@ -99,6 +99,69 @@ def test_topk_threshold_keeps_ties():
         topk_threshold(x, 5)
 
 
+def _hard_payload(shape, seed=0):
+    """Normal rows plus, in the first five rows: all zeros, ±0 beside
+    normals, subnormals only, a magnitude tie across two thirds of the row,
+    and ±inf."""
+    x = np.array(jax.random.normal(jax.random.key(seed), shape, jnp.float32))
+    rows = x.reshape(-1, shape[-1])
+    n = shape[-1]
+    rows[0] = 0.0
+    rows[1, : n // 2] = np.where(np.arange(n // 2) % 2, 0.0, -0.0)
+    rows[2] *= np.float32(1e-40)
+    rows[3, : n // 3] = 0.5
+    rows[3, n // 3: 2 * n // 3] = -0.5
+    rows[4, :7] = np.inf
+    rows[4, 7:20] = -np.inf
+    return jnp.asarray(x)
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.int32)
+
+
+# m = 75 and 700 overhang the kernel's row blocks (72 rows at n = 9,347, 624
+# at n = 1,024); (2, 5, n) is a sweep input.
+@pytest.mark.parametrize("k", [1, 512, "n"])
+@pytest.mark.parametrize("shape", [(75, 9347), (700, 1024), (2, 5, 9347)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_topk_threshold_kernel_is_bitwise_jnp(shape, k):
+    x = _hard_payload(shape, seed=len(shape))
+    k = shape[-1] if k == "n" else k
+    want = dispatch.topk_threshold(x, k, backend="jnp")
+    got = dispatch.topk_threshold(x, k, backend="interpret")
+    assert got.shape == want.shape == shape[:-1]
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    if k == 1:
+        assert float(got.reshape(-1)[4]) == np.inf
+    assert float(got.reshape(-1)[0]) == 0.0
+
+
+@pytest.mark.parametrize("backend", ["jnp", "interpret"])
+def test_topk_threshold_k_out_of_range(backend):
+    x = _x(3, 16)
+    for k in (0, 17):
+        with pytest.raises(ValueError, match="1 <= k <= 16"):
+            dispatch.topk_threshold(x, k, backend=backend)
+
+
+def test_topk_encode_and_reduce_kernel_thresholds_are_bitwise_jnp():
+    """The threshold is the only thing the backend changes in ``encode``;
+    in ``reduce_mean`` the payload is eighths up to 8 in magnitude, so every
+    order of the server sum is exact and any selection difference shows."""
+    tr = topk(512)
+    x = _hard_payload((75, 9347), seed=9)
+    s_j, r_j = tr.encode(x, backend="jnp")
+    s_i, r_i = tr.encode(x, backend="interpret")
+    np.testing.assert_array_equal(_bits(s_i), _bits(s_j))
+    np.testing.assert_array_equal(_bits(r_i), _bits(r_j))
+    eighths = jnp.round(jnp.clip(x, -8.0, 8.0) * 8.0) / 8.0
+    mean_j, res_j = tr.reduce_mean(eighths, backend="jnp")
+    mean_i, res_i = tr.reduce_mean(eighths, backend="interpret")
+    np.testing.assert_array_equal(_bits(mean_i), _bits(mean_j))
+    np.testing.assert_array_equal(_bits(res_i), _bits(res_j))
+
+
 def test_int8_roundtrip_error_is_half_ulp_of_the_row_scale():
     x = _x(5, 64, seed=1) * jnp.asarray([1e-3, 1.0, 1e3, 1e-6, 42.0])[:, None]
     q, scale = quantize_int8(x)

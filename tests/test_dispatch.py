@@ -230,7 +230,8 @@ def test_row_mean_rejects_1d():
 
 POLICY_N = 9347
 RE_BLOCKED = ("decay_accum", "scale_rows", "momentum", "adam",
-              "consensus_mix", "consensus_gather", "row_mean", "topk_scatter")
+              "consensus_mix", "consensus_gather", "row_mean", "topk_threshold",
+              "topk_scatter")
 
 
 def _run_primitive(name, g, backend):
@@ -257,6 +258,8 @@ def _run_primitive(name, g, backend):
         return dispatch.consensus_gather(g, nl.idx, w_nl, backend=backend)
     if name == "row_mean":
         return dispatch.row_mean(g, backend=backend)
+    if name == "topk_threshold":
+        return dispatch.topk_threshold(g, 50, backend=backend)
     if name == "topk_scatter":
         from repro.comm import topk_threshold
 
@@ -276,6 +279,7 @@ def test_reblocked_kernel_parity_at_policy_width(name, m):
 
 
 @pytest.mark.parametrize("name,n", [("row_mean", POLICY_N),
+                                    ("topk_threshold", POLICY_N),
                                     ("topk_scatter", POLICY_N),
                                     ("consensus_mix", 300)])
 def test_reducing_kernels_mask_the_overhanging_agent_block(name, n):

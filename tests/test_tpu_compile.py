@@ -28,6 +28,7 @@ N = 9347     # flat policy width
 M = 1024     # agents per chip in the training cells
 M_GOSSIP = 10_000
 K_GOSSIP = 8
+K_TOPK = 512  # entries a top-k payload row keeps in the 10k cell
 
 
 @pytest.fixture(scope="module")
@@ -75,6 +76,9 @@ def _primitive(name, m, sds):
         )
     if name == "row_mean":
         return lambda g: dispatch.row_mean(g, backend="pallas"), (buf,)
+    if name == "topk_threshold":
+        return lambda x: dispatch.topk_threshold(x, K_TOPK, backend="pallas"), (
+            buf,)
     if name == "topk_scatter":
         return lambda x, t: dispatch.topk_scatter(x, t, backend="pallas"), (
             buf, col)
@@ -93,6 +97,7 @@ def test_policy_width_is_the_compiled_width():
     ("decay_accum", M), ("scale_rows", M), ("momentum", M), ("adam", M),
     ("consensus_mix", M), ("consensus_gather", M), ("row_mean", M),
     ("topk_scatter", M), ("consensus_gather", M_GOSSIP),
+    ("topk_threshold", M), ("topk_threshold", M_GOSSIP),
 ])
 def test_primitive_compiles_for_v5e(one_chip, name, m):
     def sds(shape, dtype=jnp.float32):
